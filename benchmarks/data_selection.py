@@ -32,4 +32,7 @@ def run(seed: int = 0) -> dict:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import setup_compile_cache
+
+    setup_compile_cache()
     run()

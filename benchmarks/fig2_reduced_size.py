@@ -42,4 +42,7 @@ def run(n=4096, n_features=512, seed=0, rs=tuple(range(2, 21, 2))) -> dict:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import setup_compile_cache
+
+    setup_compile_cache()
     run()

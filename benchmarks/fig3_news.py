@@ -75,4 +75,7 @@ def run(days=16, n_range=(800, 6000), seed=0) -> dict:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import setup_compile_cache
+
+    setup_compile_cache()
     run()

@@ -64,4 +64,7 @@ def main() -> int:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import setup_compile_cache
+
+    setup_compile_cache()
     sys.exit(main())

@@ -96,4 +96,7 @@ def run(scale: float = 0.25, seed: int = 0, objective: str = "coverage") -> dict
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import setup_compile_cache
+
+    setup_compile_cache()
     run()

@@ -25,6 +25,9 @@ from repro.core import (
 from repro import api, obs
 from repro.core.sparsify import ss_sparsify, summarize
 from repro.data import clustered_embeddings, news_day
+from repro.compile_cache import setup_compile_cache
+
+setup_compile_cache()
 
 N, K = 4096, 10
 BACKEND = sys.argv[1] if len(sys.argv) > 1 else "oracle"

@@ -95,4 +95,7 @@ def main() -> int:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import setup_compile_cache
+
+    setup_compile_cache()
     raise SystemExit(main())

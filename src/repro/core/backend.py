@@ -13,9 +13,9 @@ point:
   on CPU).  Every shipped objective provides kernels for every configuration
   (FeatureCoverage with and without ``feat_w``, FacilityLocation, and the
   matrix-free StreamingFacilityLocation, whose kernels compute similarity
-  tiles on the fly from embedding rows — see :mod:`repro.kernels.fl_stream`);
-  the oracle fallback remains only as the safety net for *future* objectives
-  that have not implemented the hooks yet.
+  tiles on the fly from embedding rows — see :mod:`repro.kernels.fl_stream`).
+  An objective without a kernel hook is an error under this backend, never a
+  silent drop to the oracle: a run that says "pallas" ran the kernels.
 - ``sharded`` — shard_map over a device mesh: the whole SS loop runs
   distributed via the per-shard function views declared on the objective
   (see :mod:`repro.core.distributed`).
@@ -52,12 +52,21 @@ Array = jax.Array
 def default_pallas_interpret() -> bool:
     """Pallas interpret mode unless we are actually on TPU.
 
-    ``REPRO_PALLAS_INTERPRET=1`` forces interpret mode (CI / CPU correctness
-    path); ``=0`` forces the compiled kernel.
+    ``REPRO_PALLAS_INTERPRET=1`` forces interpret mode — the CPU / CI
+    correctness switch, refused when the default backend is a TPU so that a
+    chip run always executes the compiled kernels; ``=0`` forces the
+    compiled kernel.
     """
-    if os.environ.get("REPRO_PALLAS_INTERPRET"):
-        return os.environ["REPRO_PALLAS_INTERPRET"] == "1"
-    return jax.default_backend() != "tpu"
+    on_tpu = jax.default_backend() == "tpu"
+    env = os.environ.get("REPRO_PALLAS_INTERPRET")
+    if env == "1" and on_tpu:
+        raise RuntimeError(
+            "REPRO_PALLAS_INTERPRET=1 is a CPU/CI switch; on a TPU the pallas "
+            "backend runs the compiled kernels (unset the variable)"
+        )
+    if env:
+        return env == "1"
+    return not on_tpu
 
 
 @dataclasses.dataclass(frozen=True)
@@ -223,11 +232,11 @@ class PallasBackend(Backend):
     """Fused Pallas kernels.
 
     ``interpret=None`` auto-detects (interpret mode off-TPU, honoring
-    ``REPRO_PALLAS_INTERPRET``).  Objectives advertise kernel support via
-    their ``pallas_divergence`` / ``pallas_gains`` hooks; both shipped
-    objectives implement them for every configuration, so nothing falls back
-    in-tree — a ``None`` return from an objective that has no kernel still
-    drops to the oracle path, keeping the backend always safe to select.
+    ``REPRO_PALLAS_INTERPRET``).  Objectives provide their kernels through
+    the ``pallas_divergence`` / ``pallas_gains`` hooks; every shipped
+    objective implements them for every configuration.  A hook that returns
+    ``None`` (no kernel) raises :class:`NotImplementedError` naming the
+    objective and the primitive — there is no oracle fallback to hide it.
     """
 
     name = "pallas"
@@ -238,9 +247,18 @@ class PallasBackend(Backend):
             return default_pallas_interpret()
         return self.interpret
 
+    def _kernel(self, fn: SubmodularFunction, primitive: str, out):
+        if out is None:
+            raise NotImplementedError(
+                f"{type(fn).__name__} has no Pallas kernel for {primitive} "
+                f"(its pallas hook returned None); implement the hook or run "
+                f"it under backend='oracle'"
+            )
+        return out
+
     def gains(self, fn: SubmodularFunction, state, **kw) -> Array:
         out = fn.pallas_gains(state, interpret=self._interpret(), **kw)
-        return fn.gains(state) if out is None else out
+        return self._kernel(fn, "gains", out)
 
     def gains_compact(
         self, fn: SubmodularFunction, state, cand_idx: Array, **kw
@@ -248,7 +266,7 @@ class PallasBackend(Backend):
         out = fn.pallas_gains(
             state, interpret=self._interpret(), cand_idx=cand_idx, **kw
         )
-        return fn.gains_compact(state, cand_idx) if out is None else out
+        return self._kernel(fn, "gains_compact", out)
 
     def divergence(
         self,
@@ -265,9 +283,7 @@ class PallasBackend(Backend):
             probes, residual, state, probe_mask,
             interpret=self._interpret(), **kw,
         )
-        if out is None:
-            return graph.divergence(fn, probes, probe_mask, residual, state)
-        return out
+        return self._kernel(fn, "divergence", out)
 
     def divergence_compact(
         self,
@@ -285,11 +301,7 @@ class PallasBackend(Backend):
             probes, residual, state, probe_mask,
             interpret=self._interpret(), cand_idx=cand_idx, **kw,
         )
-        if out is None:
-            return graph.divergence_compact(
-                fn, probes, cand_idx, probe_mask, residual, state
-            )
-        return out
+        return self._kernel(fn, "divergence_compact", out)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -315,7 +327,7 @@ class ShardedBackend(Backend):
     def _mesh(self) -> jax.sharding.Mesh:
         if self.mesh is not None:
             return self.mesh
-        from repro.compat import make_mesh
+        from repro.core.distributed import make_mesh
 
         return make_mesh((jax.device_count(),), (self.data_axis,))
 

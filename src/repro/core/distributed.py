@@ -50,7 +50,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core.functions import NEG, FeatureCoverage, SubmodularFunction
 from repro.core.greedy import (
     GreedyResult,
@@ -62,6 +61,16 @@ from repro.core.sparsify import SSResult, bucket_schedule, max_rounds, probe_cou
 
 Array = jax.Array
 INF = -NEG
+
+
+def make_mesh(shape, axes) -> Mesh:
+    """A device mesh with Auto axis types (jax.make_mesh defaults to
+    Explicit), the sharding mode every shard_map in this repository is
+    written for."""
+    return jax.make_mesh(
+        tuple(shape), tuple(axes),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+    )
 
 
 def _as_objective(fn, phi: str = "sqrt") -> SubmodularFunction:
@@ -305,11 +314,12 @@ def ss_sparsify_sharded(
     scalar_spec = P(pod_axis) if pod_axis else P()
     trace_spec = P(pod_axis, None) if pod_axis else P()
     state_in = state if has_state else jnp.zeros((1,), jnp.float32)
-    fn_sm = shard_map(
+    fn_sm = jax.shard_map(
         kernel,
         mesh=mesh,
         in_specs=(keys_spec, mask_spec, P()) + specs,
         out_specs=(mask_spec, mask_spec, scalar_spec, scalar_spec, trace_spec),
+        check_vma=False,
     )
     vprime, div, eps, rounds, trace = fn_sm(keys, alive0, state_in, *arrays)
     eps_hat = jnp.max(eps)
@@ -546,11 +556,12 @@ def _select_sharded(
         (st_f, _), (sel, gains) = jax.lax.scan(step, (st0, avail0), xs)
         return sel, gains, st_f
 
-    fn_sm = shard_map(
+    fn_sm = jax.shard_map(
         kernel,
         mesh=mesh,
         in_specs=(mask_spec, P()) + specs,
         out_specs=(P(), P(), P()),
+        check_vma=False,
     )
     sel, gains, st_f = fn_sm(alive0, state0, *arrays)
     return GreedyResult(sel, gains, fn.value(st_f), st_f)

@@ -221,9 +221,10 @@ class SubmodularFunction(abc.ABC):
             self, state, cand_idx
         )
 
-    # -- pallas hooks (optional) -------------------------------------------
+    # -- pallas hooks ------------------------------------------------------
     # Returning None means "no fused kernel for this configuration"; the
-    # pallas backend then falls back to the jnp oracle.  ``interpret`` selects
+    # pallas backend then raises NotImplementedError naming the objective and
+    # the primitive (it never drops to the jnp oracle).  ``interpret`` selects
     # Pallas interpret mode (CPU correctness path) vs. the compiled TPU kernel.
 
     def pallas_divergence(
@@ -241,9 +242,8 @@ class SubmodularFunction(abc.ABC):
 
         With ``cand_idx`` (k,) the output is restricted to the compacted
         candidate buffer — shape (k,) instead of (n,) — and the kernel grid
-        should only cover the gathered candidates.  Returning None for a
-        non-None ``cand_idx`` drops the pallas backend to the oracle gather
-        path (always correct, never faster)."""
+        should only cover the gathered candidates.  Returning None (for any
+        ``cand_idx``) makes the pallas backend raise."""
         return None
 
     def pallas_gains(
@@ -258,9 +258,8 @@ class SubmodularFunction(abc.ABC):
 
         With ``cand_idx`` (k,) the output is restricted to the compacted
         candidate buffer — shape (k,) — and the kernel grid should only
-        cover the gathered candidates.  Returning None for a non-None
-        ``cand_idx`` drops the pallas backend to the oracle
-        ``gains_compact`` path (always correct, never faster)."""
+        cover the gathered candidates.  Returning None (for any ``cand_idx``)
+        makes the pallas backend raise."""
         return None
 
     # -- shard hooks (optional) --------------------------------------------
@@ -597,12 +596,17 @@ class FeatureCoverage(SubmodularFunction):
         # Pod-global coverage totals: everything downstream is local given C.
         C = jax.lax.psum(jnp.sum(self.W, axis=0), axis)          # (F,)
         cap = self.alpha * C if self.phi == "satcov" else None
-        phiC = self._wsum(_phi(self.phi, C, cap))
-        return (C, cap, phiC)
+        return (C, cap)
 
     def shard_residuals(self, ctx) -> Array:
-        C, cap, phiC = ctx
-        return phiC - self._wsum(_phi(self.phi, C[None, :] - self.W, cap))
+        # Same arithmetic as the dense residual_gains: difference per feature,
+        # then the weighted sum.  Subtracting the two O(F)-magnitude sums
+        # instead loses ~1e-4 of an O(1) residual to f32 cancellation.
+        C, cap = ctx
+        return self._wsum(
+            _phi(self.phi, C[None, :], cap)
+            - _phi(self.phi, C[None, :] - self.W, cap)
+        )
 
     def shard_payloads(self, idx: Array, state: Array | None = None) -> Array:
         # The payload *is* the probe's conditional coverage row c(S + u):
@@ -614,7 +618,7 @@ class FeatureCoverage(SubmodularFunction):
         return state[None, :] + self.W[idx]
 
     def shard_payload_gains(self, payloads: Array, ctx) -> Array:
-        _, cap, _ = ctx
+        _, cap = ctx
         phi_cu = self._wsum(_phi(self.phi, payloads, cap))       # (m,)
         both = payloads[:, None, :] + self.W[None, :, :]         # (m, nl, F)
         return self._wsum(_phi(self.phi, both, cap)) - phi_cu[:, None]
@@ -625,7 +629,7 @@ class FeatureCoverage(SubmodularFunction):
     def shard_gains(self, state: Array, ctx) -> Array:
         # Same expression as the dense gains, with the pod-global satcov cap
         # from ctx (the local W slice would under-saturate it).
-        _, cap, _ = ctx
+        _, cap = ctx
         return self._wsum(
             _phi(self.phi, state[None, :] + self.W, cap)
             - _phi(self.phi, state[None, :], cap)

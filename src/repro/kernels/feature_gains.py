@@ -22,7 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import pallas_tpu_compiler_params
 from repro.kernels.ss_weights import _phi, _round_up
 
 Array = jax.Array
@@ -107,7 +106,7 @@ def feature_gains_kernel(
         ],
         out_specs=pl.BlockSpec((1, bn), lambda i, j: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, npad), f32),
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

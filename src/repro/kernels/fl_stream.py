@@ -61,7 +61,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import pallas_tpu_compiler_params
 from repro.kernels.ss_weights import _round_up
 
 Array = jax.Array
@@ -101,26 +100,19 @@ def _fl_stream_kernel(
         ),
         0.0,
     )                                          # (BI, BN)
-    mu = mu_ref[...].astype(jnp.float32)      # (RP, BI)
+    n_chunks = mu_ref.shape[0] // probe_chunk
 
-    rp = mu.shape[0]
-    n_chunks = rp // probe_chunk
-
-    def body(j, acc):
+    def body(j, carry):
         # Probe chunk (PC, BI) against the whole candidate tile (BI, BN):
-        # contrib[p, v] = sum_i max(sim[i, v] - mu[p, i], 0)
-        mu_j = jax.lax.dynamic_slice_in_dim(mu, j * probe_chunk, probe_chunk, 0)
+        # contrib[p, v] = sum_i max(sim[i, v] - mu[p, i], 0).  The chunk is
+        # sliced on the refs (not on loaded values), which Mosaic lowers.
+        rows = pl.ds(pl.multiple_of(j * probe_chunk, probe_chunk), probe_chunk)
+        mu_j = mu_ref[rows, :].astype(jnp.float32)
         val = jnp.maximum(sim[None, :, :] - mu_j[:, :, None], 0.0)
-        contrib = jnp.sum(val, axis=1)        # (PC, BN)
-        return jax.lax.dynamic_update_slice_in_dim(
-            acc,
-            jax.lax.dynamic_slice_in_dim(acc, j * probe_chunk, probe_chunk, 0)
-            + contrib,
-            j * probe_chunk,
-            0,
-        )
+        acc_ref[rows, :] += jnp.sum(val, axis=1)   # (PC, BN)
+        return carry
 
-    acc_ref[...] = jax.lax.fori_loop(0, n_chunks, body, acc_ref[...])
+    jax.lax.fori_loop(0, n_chunks, body, 0)
 
     @pl.when(i_i == n_i_blocks - 1)
     def _finish():
@@ -190,7 +182,7 @@ def fl_stream_divergence_kernel(
         out_specs=pl.BlockSpec((1, bn), lambda i, j: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, npad), f32),
         scratch_shapes=[pltpu.VMEM((rp, bn), f32)],
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -36,8 +36,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import pallas_tpu_compiler_params
-
 Array = jax.Array
 
 NEG_INF = -1e30
@@ -160,7 +158,7 @@ def flash_attention(
             pltpu.VMEM((bq, 128), jnp.float32),
             pltpu.VMEM((bq, 128), jnp.float32),
         ],
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -38,8 +38,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import pallas_tpu_compiler_params
-
 Array = jax.Array
 
 
@@ -78,28 +76,22 @@ def _ss_divergence_kernel(
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     w = w_ref[...].astype(jnp.float32)        # (BN, BF)
-    cu = cu_ref[...].astype(jnp.float32)      # (RP, BF)
     cap = cap_ref[...].astype(jnp.float32)    # (1, BF)
     fw = fw_ref[...].astype(jnp.float32)      # (1, BF)
 
-    rp = cu.shape[0]
-    n_chunks = rp // probe_chunk
+    n_chunks = cu_ref.shape[0] // probe_chunk
 
-    def body(j, acc):
+    def body(j, carry):
         # Probe chunk (PC, BF) against the whole candidate tile (BN, BF):
-        # contrib[p, v] = sum_f w_f * phi(cu[p, f] + w[v, f])
-        cu_j = jax.lax.dynamic_slice_in_dim(cu, j * probe_chunk, probe_chunk, 0)
+        # contrib[p, v] = sum_f w_f * phi(cu[p, f] + w[v, f]).  The chunk is
+        # sliced on the refs (not on loaded values), which Mosaic lowers.
+        rows = pl.ds(pl.multiple_of(j * probe_chunk, probe_chunk), probe_chunk)
+        cu_j = cu_ref[rows, :].astype(jnp.float32)
         val = _phi(phi, cu_j[:, None, :] + w[None, :, :], cap[None, :, :])
-        contrib = jnp.sum(val * fw[None, :, :], axis=-1)  # (PC, BN)
-        return jax.lax.dynamic_update_slice_in_dim(
-            acc,
-            jax.lax.dynamic_slice_in_dim(acc, j * probe_chunk, probe_chunk, 0)
-            + contrib,
-            j * probe_chunk,
-            0,
-        )
+        acc_ref[rows, :] += jnp.sum(val * fw[None, :, :], axis=-1)  # (PC, BN)
+        return carry
 
-    acc_ref[...] = jax.lax.fori_loop(0, n_chunks, body, acc_ref[...])
+    jax.lax.fori_loop(0, n_chunks, body, 0)
 
     @pl.when(i_f == n_f_blocks - 1)
     def _finish():
@@ -181,7 +173,7 @@ def ss_divergence_kernel(
         out_specs=pl.BlockSpec((1, bn), lambda i, j: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, npad), f32),
         scratch_shapes=[pltpu.VMEM((rp, bn), f32)],
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

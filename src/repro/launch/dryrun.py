@@ -76,8 +76,7 @@ def run_cell(arch: str, shape_name: str, mesh, mesh_name: str) -> dict:
         out_shardings=cell.out_shardings,
         donate_argnums=cell.donate_argnums,
     )
-    from repro.compat import set_mesh
-    with set_mesh(mesh):   # activates the P()-based constraints
+    with jax.set_mesh(mesh):   # activates the P()-based constraints
         lowered = fn.lower(*cell.args)
         compiled = lowered.compile()
     t_compile = time.time() - t0

@@ -6,7 +6,7 @@ from __future__ import annotations
 
 from jax.sharding import Mesh
 
-from repro.compat import make_mesh
+from repro.core.distributed import make_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

@@ -80,8 +80,7 @@ def main(argv=None) -> int:
     pipe = Pipeline(dc, seed=args.seed)
 
     state_shape = abstract_train_state(cfg, tc)
-    from repro.compat import set_mesh
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         step_fn, state_sh, batch_sharding = shard_train_step(
             mesh, cfg, tc, state_shape
         )

@@ -211,10 +211,8 @@ def constrain(x, *entries):
     non-dividing.  This is how the model code pins activation shardings
     (batch over pod x data, vocab/heads over model) without hard-coding a
     mesh."""
-    from repro.compat import get_abstract_mesh
-
-    mesh = get_abstract_mesh()
-    if mesh is None or not mesh.axis_names:
+    mesh = jax.sharding.get_abstract_mesh()
+    if not mesh.axis_names:
         return x
     ok = usable_axes(mesh)
     cleaned = []

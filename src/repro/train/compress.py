@@ -31,7 +31,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.models.sharding import POD, batch_axes
 
 Array = jax.Array
@@ -135,12 +134,13 @@ def make_compressed_train_step(mesh: Mesh, cfg, tc, cc: CompressConfig):
             "loss": P(), "aux": P(), "grad_norm": P(), "lr": P(),
             "compress_density": P(),
         }
-        return shard_map(
+        return jax.shard_map(
             step,
             mesh=mesh,
             in_specs=(state_specs, batch_specs),
             out_specs=(state_specs, metric_specs),
             axis_names={POD},
+            check_vma=False,
         )(state, batch)
 
     return wrap

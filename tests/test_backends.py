@@ -1,9 +1,10 @@
 """Backend dispatch layer tests: registry contract + numerical parity of the
 oracle / pallas (interpret) / sharded execution backends on both objectives
 and all phi variants.  Every shipped configuration — FeatureCoverage with and
-without feat_w feature weights, and FacilityLocation — now has a fused
-kernel, so the pallas legs exercise real kernels, never the oracle fallback
-(test_pallas_hooks_no_fallback pins that).
+without feat_w feature weights, and FacilityLocation — has a fused kernel,
+so the pallas legs exercise real kernels (test_pallas_hooks_no_fallback pins
+that), and an objective without one is an error under the pallas backend,
+never a silent drop to the oracle (test_pallas_backend_raises_without_kernel).
 
 Multi-device sharded parity lives in test_distributed.py (needs forced host
 devices); here the sharded backend runs on the default single-device mesh —
@@ -117,6 +118,54 @@ def test_pallas_hooks_no_fallback(name):
     assert out is not None and out.shape == (fn.n,)
     g = fn.pallas_gains(fn.empty_state(), interpret=True)
     assert g is not None and g.shape == (fn.n,)
+
+
+@pytest.mark.parametrize(
+    "primitive", ["gains", "gains_compact", "divergence", "divergence_compact"]
+)
+def test_pallas_backend_raises_without_kernel(primitive, monkeypatch):
+    """A hook that returns None (the SubmodularFunction default) makes the
+    pallas backend raise, naming the objective and the primitive."""
+    from repro.core.functions import SubmodularFunction
+
+    for hook in ("pallas_gains", "pallas_divergence"):
+        monkeypatch.setattr(
+            FeatureCoverage, hook, getattr(SubmodularFunction, hook)
+        )
+    fn = make_fc()
+    be = PallasBackend(interpret=True)
+    probes = jnp.asarray([1, 42, 99])
+    cand_idx = jnp.asarray([0, 5, 7])
+    calls = {
+        "gains": lambda: be.gains(fn, fn.empty_state()),
+        "gains_compact": lambda: be.gains_compact(
+            fn, fn.empty_state(), cand_idx),
+        "divergence": lambda: be.divergence(fn, probes),
+        "divergence_compact": lambda: be.divergence_compact(
+            fn, probes, cand_idx),
+    }
+    with pytest.raises(NotImplementedError,
+                       match=f"FeatureCoverage .* for {primitive} "):
+        calls[primitive]()
+
+
+def test_interpret_env_refused_on_tpu(monkeypatch):
+    """REPRO_PALLAS_INTERPRET=1 is the CPU/CI switch: honored off-TPU,
+    refused when the default backend is a TPU."""
+    from repro.core.backend import default_pallas_interpret
+
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    assert default_pallas_interpret() is True
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="REPRO_PALLAS_INTERPRET"):
+        default_pallas_interpret()
+    with pytest.raises(RuntimeError, match="REPRO_PALLAS_INTERPRET"):
+        PallasBackend().gains(make_fc(), jnp.zeros((64,)))
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
+    assert default_pallas_interpret() is False
+    monkeypatch.delenv("REPRO_PALLAS_INTERPRET")
+    assert default_pallas_interpret() is False
 
 
 # ------------------------------------------------------ divergence parity ----

@@ -258,7 +258,7 @@ def test_sharded_stochastic_matches_dense_compact_1dev():
     """The distributed sampler is selection-for-selection identical to the
     dense compact path under the same key (1-device mesh; the 8-device case
     is pinned in tests/test_distributed.py)."""
-    from repro.compat import make_mesh
+    from repro.core.distributed import make_mesh
 
     mesh = make_mesh((jax.device_count(),), ("data",))
     for fn in (make_fc(14, n=256, F=32), make_fl(15, n=256)):
@@ -274,7 +274,7 @@ def test_sharded_stochastic_matches_dense_full_width():
     """When the dense plan is full-width (live count fits no sub-n bucket,
     or compact=False), the sharded sampler switches to the ground frame and
     still matches the dense path under the same key."""
-    from repro.compat import make_mesh
+    from repro.core.distributed import make_mesh
 
     mesh = make_mesh((jax.device_count(),), ("data",))
     be = ShardedBackend(mesh=mesh)
@@ -313,7 +313,7 @@ def test_stochastic_full_width_s_derives_from_live_count():
 
 
 def test_sharded_stochastic_rejects_pod_axis():
-    from repro.compat import make_mesh
+    from repro.core.distributed import make_mesh
 
     mesh = make_mesh((1, 1), ("pod", "data"))
     fn = make_fc(16, n=64, F=8)

@@ -29,7 +29,7 @@ def test_sharded_ss_matches_full_greedy():
         import jax, jax.numpy as jnp
         from repro.core.distributed import summarize_sharded
         from repro.core import FeatureCoverage, greedy
-        from repro.compat import make_mesh
+        from repro.core.distributed import make_mesh
         from repro.data import news_day
 
         W = news_day(0, 1024, 128)
@@ -50,7 +50,7 @@ def test_sharded_ss_hierarchical_pods():
         import jax, jax.numpy as jnp
         from repro.core.distributed import summarize_sharded
         from repro.core import FeatureCoverage, greedy
-        from repro.compat import make_mesh
+        from repro.core.distributed import make_mesh
         from repro.data import news_day
 
         W = news_day(1, 1024, 128)
@@ -73,7 +73,7 @@ def test_sharded_backend_facility_location_multidevice():
     out = run_sub("""
         import jax, jax.numpy as jnp
         from repro.core import FacilityLocation, ShardedBackend, greedy, ss_sparsify
-        from repro.compat import make_mesh
+        from repro.core.distributed import make_mesh
 
         X = jax.random.normal(jax.random.PRNGKey(1), (512, 16))
         fn = FacilityLocation.from_features(X, kernel="rbf")
@@ -101,7 +101,7 @@ def test_sharded_backend_fl_stream_multidevice():
         import jax, jax.numpy as jnp, numpy as np
         from repro.core import (FacilityLocation, ShardedBackend,
                                 StreamingFacilityLocation, greedy, ss_sparsify)
-        from repro.compat import make_mesh, shard_map
+        from repro.core.distributed import make_mesh
         from jax.sharding import PartitionSpec as P
 
         mesh = make_mesh((8,), ("data",))
@@ -114,8 +114,8 @@ def test_sharded_backend_fl_stream_multidevice():
         def res_kernel(*arrs):
             loc = rebuild(*arrs)
             return loc.shard_residuals(loc.shard_init("data"))
-        res = shard_map(res_kernel, mesh=mesh, in_specs=specs,
-                        out_specs=P("data"))(*arrays)
+        res = jax.shard_map(res_kernel, mesh=mesh, in_specs=specs,
+                        out_specs=P("data"), check_vma=False)(*arrays)
         np.testing.assert_allclose(np.asarray(res),
                                    np.asarray(dense.residual_gains()),
                                    rtol=1e-4, atol=1e-4)
@@ -138,12 +138,15 @@ def test_sharded_backend_fl_stream_multidevice():
 def test_sharded_backend_objective_generic():
     """The sharded loop is objective-generic: both objectives run through the
     same shard_map kernel via their shard hooks, and per-shard residuals
-    match the dense oracle exactly."""
+    match the dense oracle.  The shard residuals use the dense arithmetic
+    (per-feature difference, then the sum); only the pod-global coverage
+    total C is summed in a different order (per-shard sums, then psum), which
+    moves an O(1) residual by ~1e-5, well inside rtol=atol=1e-4."""
     out = run_sub("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.core import FacilityLocation, FeatureCoverage
         from repro.core.distributed import ss_sparsify_sharded
-        from repro.compat import make_mesh, shard_map
+        from repro.core.distributed import make_mesh
         from jax.sharding import PartitionSpec as P
 
         mesh = make_mesh((8,), ("data",))
@@ -160,8 +163,8 @@ def test_sharded_backend_objective_generic():
             def res_kernel(*arrs):
                 loc = rebuild(*arrs)
                 return loc.shard_residuals(loc.shard_init("data"))
-            res = shard_map(res_kernel, mesh=mesh, in_specs=specs,
-                            out_specs=P("data"))(*arrays)
+            res = jax.shard_map(res_kernel, mesh=mesh, in_specs=specs,
+                            out_specs=P("data"), check_vma=False)(*arrays)
             np.testing.assert_allclose(np.asarray(res),
                                        np.asarray(fn.residual_gains()),
                                        rtol=1e-4, atol=1e-4)
@@ -183,7 +186,7 @@ def test_sharded_stochastic_greedy_matches_dense_compact():
         import jax, jax.numpy as jnp, numpy as np
         from repro.core import (FacilityLocation, FeatureCoverage,
                                 ShardedBackend, ss_sparsify, stochastic_greedy)
-        from repro.compat import make_mesh
+        from repro.core.distributed import make_mesh
 
         mesh = make_mesh((8,), ("data",))
         be = ShardedBackend(mesh=mesh)
@@ -236,7 +239,7 @@ def test_sharded_exact_greedy_matches_dense():
         import jax, jax.numpy as jnp, numpy as np
         from repro.core import (FacilityLocation, FeatureCoverage,
                                 ShardedBackend, greedy, ss_sparsify)
-        from repro.compat import make_mesh
+        from repro.core.distributed import make_mesh
 
         mesh = make_mesh((8,), ("data",))
         be = ShardedBackend(mesh=mesh)
@@ -277,7 +280,7 @@ def test_sharded_ss_conditional_and_importance():
         import jax, jax.numpy as jnp
         from repro.core import (FacilityLocation, FeatureCoverage,
                                 ShardedBackend, greedy, ss_sparsify)
-        from repro.compat import make_mesh
+        from repro.core.distributed import make_mesh
 
         mesh = make_mesh((8,), ("data",))
         be = ShardedBackend(mesh=mesh)
@@ -305,12 +308,6 @@ def test_sharded_ss_conditional_and_importance():
     assert "COND_IMP_OK" in out
 
 
-@pytest.mark.xfail(
-    strict=False,
-    reason="container jax (0.4.37) lacks the partial-manual shard_map "
-    "axis-type introspection the compressed pod train step needs "
-    "(pre-existing since PR 1, see CHANGES.md); passes on newer jax",
-)
 def test_compressed_pod_training_converges():
     out = run_sub("""
         import jax, jax.numpy as jnp
@@ -318,7 +315,7 @@ def test_compressed_pod_training_converges():
         from repro.train import (TrainConfig, make_train_state, CompressConfig,
                                  init_error_state, make_compressed_train_step)
 
-        from repro.compat import make_mesh
+        from repro.core.distributed import make_mesh
         mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         cfg = configs.smoke("llama3.2-3b")
         tc = TrainConfig(optimizer="adamw", lr=1e-3, warmup_steps=1,
@@ -326,8 +323,7 @@ def test_compressed_pod_training_converges():
         cc = CompressConfig(ratio=0.1, block=64)
         state = make_train_state(jax.random.PRNGKey(0), cfg, tc)
         state["error"] = init_error_state(state["params"])
-        from repro.compat import set_mesh
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             step = jax.jit(make_compressed_train_step(mesh, cfg, tc, cc))
             toks = jax.random.randint(jax.random.PRNGKey(1), (8, 32), 0,
                                       cfg.vocab_size)
@@ -358,8 +354,7 @@ def test_sharded_train_step_on_mesh():
         tc = TrainConfig(optimizer="adafactor", num_microbatches=2,
                          warmup_steps=1, total_steps=8, lr=1e-3)
         shape = abstract_train_state(cfg, tc)
-        from repro.compat import set_mesh
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             fn, state_sh, batch_sh = shard_train_step(mesh, cfg, tc, shape)
             state = make_train_state(jax.random.PRNGKey(0), cfg, tc)
             state = jax.device_put(state, state_sh)
