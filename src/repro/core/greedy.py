@@ -61,9 +61,11 @@ def _record_greedy(sp, res: "GreedyResult", k: int, backend: str,
                    wall_s: float, *, selector: str, batched: bool) -> None:
     """Fill a selection span + metrics from a finished (host-read) result.
     The per-step gain trajectory is read from ``GreedyResult.gains`` *after*
-    the compiled loop returns — pure observation, never an in-loop sync."""
-    gains = np.asarray(res.gains)
-    value = np.asarray(res.value)
+    the compiled loop returns — pure observation, never an in-loop sync —
+    in one device read, counted as the tracer's own (``obs_syncs``)."""
+    gains, value = obs.device_get(
+        "greedy.record", (res.gains, res.value), instrumentation=True
+    )
     if batched:
         sp.set(
             B=int(gains.shape[0]),
@@ -236,7 +238,8 @@ def greedy(
     with obs.span("greedy.select") as sp:
         t0 = time.perf_counter()
         res = be.greedy(fn, k, alive=alive, state=state, compact=compact)
-        jax.block_until_ready(res.selected)
+        obs.block_until_ready("greedy.wall", res.selected,
+                              instrumentation=True)
         wall = time.perf_counter() - t0
         _record_greedy(sp, res, k, be.name, wall,
                        selector="greedy", batched=False)
@@ -377,7 +380,8 @@ def greedy_batched(
     with obs.span("greedy.select_batched", n=n, bucket=size) as sp:
         t0 = time.perf_counter()
         res = _run()
-        jax.block_until_ready(res.selected)
+        obs.block_until_ready("greedy.wall", res.selected,
+                              instrumentation=True)
         wall = time.perf_counter() - t0
         _record_greedy(sp, res, k, be.name, wall,
                        selector="greedy", batched=True)
@@ -385,7 +389,8 @@ def greedy_batched(
 
 
 # ``on_step(step_index, selected (B,), gains (B,), ok (B,))`` — arrays are
-# concrete; exhausted rows carry index 0 / gain 0 with ok=False.
+# concrete device arrays (reading one waits for its step); exhausted rows
+# carry index 0 / gain 0 with ok=False.
 StepCallback = "Callable[[int, Array, Array, Array], None]"
 
 
@@ -458,6 +463,7 @@ def _batched_step(
 
 
 @partial(jax.jit, static_argnames=("k", "size", "backend"))
+@obs.named_scope("greedy")
 def _greedy_batched(
     fn: SubmodularFunction,
     k: int,
@@ -486,11 +492,12 @@ def _greedy_batched(
 
 
 _batched_step_jit = partial(jax.jit, static_argnames=("backend",))(
-    _batched_step
+    obs.named_scope("greedy")(_batched_step)
 )
 
 
 @jax.jit
+@obs.named_scope("greedy")
 def _batched_value(fn: SubmodularFunction, state) -> Array:
     return jax.vmap(lambda f, s: f.value(s))(fn, state)
 
@@ -515,9 +522,8 @@ def _greedy_batched_stepped(
         st, avail, v, g, ok = _batched_step_jit(
             fn, st, avail, cand_idx, backend
         )
-        # Host-sync the committed step so the callback observes real values
-        # (the next launch proceeds immediately after).
-        v, g, ok = jax.block_until_ready((v, g, ok))
+        # The callback reads the committed step (its one host sync); the
+        # next launch proceeds once it returns.
         on_step(i, v, g, ok)
         sel.append(v)
         gains.append(g)
@@ -598,7 +604,8 @@ def stochastic_greedy_batched(
     with obs.span("greedy.stochastic_batched", n=n, bucket=size, s=s) as sp:
         t0 = time.perf_counter()
         res = _run()
-        jax.block_until_ready(res.selected)
+        obs.block_until_ready("greedy.wall", res.selected,
+                              instrumentation=True)
         wall = time.perf_counter() - t0
         _record_greedy(sp, res, k, be.name, wall,
                        selector="stochastic", batched=True)
@@ -617,7 +624,7 @@ def _batched_compact_plan(
     if isinstance(compact, (bool, type(None))):
         if isinstance(alive, jax.core.Tracer):
             return None, None
-        live_max = int(jnp.max(jnp.sum(alive, axis=1)))
+        live_max = _read_live_max(alive)
         return selection_bucket(n, live_max), live_max
     bound = int(compact)
     if not 0 <= bound <= n:
@@ -625,7 +632,7 @@ def _batched_compact_plan(
             f"compact live bound must be in [0, n={n}]; got {bound}"
         )
     if not isinstance(alive, jax.core.Tracer):
-        live_max = int(jnp.max(jnp.sum(alive, axis=1)))
+        live_max = _read_live_max(alive)
         if live_max > bound:
             raise ValueError(
                 f"compact live bound {bound} < max row |alive| = "
@@ -634,6 +641,15 @@ def _batched_compact_plan(
             )
         bound = live_max
     return selection_bucket(n, bound), bound
+
+
+def _read_live_max(alive: Array) -> int:
+    """The batch-max live count of a concrete (B, n) mask: one device read,
+    in its own ``greedy.plan`` span."""
+    with obs.span("greedy.plan"):
+        return int(obs.device_get(
+            "greedy.live_max", jnp.max(jnp.sum(alive, axis=1))
+        ))
 
 
 def _sg_batched_step(
@@ -734,7 +750,6 @@ def _stochastic_greedy_batched_stepped(
         st, avail, v, g, ok = _sg_batched_step_jit(
             fn, st, avail, cand_idx, step_keys[i], s, backend
         )
-        v, g, ok = jax.block_until_ready((v, g, ok))
         on_step(i, v, g, ok)
         sel.append(v)
         gains.append(g)

@@ -72,15 +72,22 @@ def _round_detail(
 
 def _record_ss(
     sp, ss: "SSResult", n: int, r: int, c: float, backend: str,
-    wall_s: float, live0, *, batched: bool,
+    wall_s: float, alive, *, batched: bool,
 ) -> None:
-    """Fill an SS span + metrics from a finished (host-read) result."""
+    """Fill an SS span + metrics from a finished result, host-read in one
+    device read (counted as the tracer's own, ``obs_syncs``).  ``alive`` is
+    the initial live mask (None = every element live)."""
     reg = obs.get_registry()
-    trace = np.asarray(ss.alive_trace)
-    rounds = np.asarray(ss.rounds)
-    eps_hat = np.asarray(ss.eps_hat)
-    vp = np.asarray(jnp.sum(ss.vprime, axis=-1))
+    live0 = None if alive is None else jnp.sum(alive, axis=-1)
+    trace, rounds, eps_hat, vp, live0 = obs.device_get(
+        "ss.record",
+        (ss.alive_trace, ss.rounds, ss.eps_hat,
+         jnp.sum(ss.vprime, axis=-1), live0),
+        instrumentation=True,
+    )
     if batched:
+        if live0 is None:
+            live0 = [n] * int(trace.shape[0])
         sp.set(
             B=int(trace.shape[0]),
             rounds=[int(x) for x in rounds],
@@ -93,10 +100,11 @@ def _record_ss(
         )
         total_rounds = int(rounds.sum())
     else:
+        live0 = n if live0 is None else int(live0)
         sp.set(
             rounds=int(rounds), eps_hat=float(eps_hat),
             vprime_size=int(vp),
-            rounds_detail=_round_detail(trace, n, r, c, int(live0), wall_s),
+            rounds_detail=_round_detail(trace, n, r, c, live0, wall_s),
         )
         total_rounds = int(rounds)
     # wall_s is the measured compute wall (t0 -> block_until_ready), the
@@ -260,10 +268,9 @@ def ss_sparsify(
             fn, key, r=r, c=c, alive=alive, state=state,
             importance=importance, compact=compact,
         )
-        jax.block_until_ready(ss.vprime)
+        obs.block_until_ready("ss.wall", ss.vprime, instrumentation=True)
         wall = time.perf_counter() - t0
-        live0 = fn.n if alive is None else int(jnp.sum(alive))
-        _record_ss(sp, ss, fn.n, r, c, be.name, wall, live0, batched=False)
+        _record_ss(sp, ss, fn.n, r, c, be.name, wall, alive, batched=False)
     return ss
 
 
@@ -435,19 +442,15 @@ def ss_sparsify_batched(
             fn, keys, r=r, c=c, alive=alive, state=state,
             importance=importance, compact=compact,
         )
-        jax.block_until_ready(ss.vprime)
+        obs.block_until_ready("ss.wall", ss.vprime, instrumentation=True)
         wall = time.perf_counter() - t0
         n = jax.tree.map(lambda x: x[0], fn).n
-        B = int(keys.shape[0])
-        if alive is None:
-            live0 = [n] * B
-        else:
-            live0 = [int(x) for x in np.asarray(jnp.sum(alive, axis=1))]
-        _record_ss(sp, ss, n, r, c, be.name, wall, live0, batched=True)
+        _record_ss(sp, ss, n, r, c, be.name, wall, alive, batched=True)
     return ss
 
 
 @partial(jax.jit, static_argnames=("r", "c", "importance", "backend", "compact"))
+@obs.named_scope("ss")
 def _sparsify_batched(
     fn: SubmodularFunction,
     keys: Array,

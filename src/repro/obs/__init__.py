@@ -8,7 +8,10 @@ Three layers, one import surface:
   bounded in-process ring buffer.  Off by default (``configure(trace=True)``
   or ``REPRO_TRACE=1``); when off the ``span()`` hooks are near-zero-cost
   no-ops and telemetry-on results are bit-identical to telemetry-off
-  (tests/test_obs.py pins this on oracle and pallas).
+  (tests/test_obs.py pins this on oracle and pallas).  Lexical spans also
+  enter ``jax.profiler.TraceAnnotation``; the served path's host↔device
+  traffic goes through :func:`device_put` / :func:`device_get` /
+  :func:`block_until_ready`, which count syncs and bytes on the open span.
 - :mod:`repro.obs.metrics` — process-wide counters / gauges / histograms
   with Prometheus text-format and JSON exporters (``repro.api.metrics()``;
   :func:`repro.obs.metrics.start_metrics_server` for a pull endpoint).
@@ -31,9 +34,13 @@ from repro.obs.metrics import (
 from repro.obs.trace import (
     Span,
     Tracer,
+    block_until_ready,
     configure,
+    device_get,
+    device_put,
     format_trace,
     get_tracer,
+    named_scope,
     span,
     trace_enabled,
     trace_summary,
@@ -49,7 +56,10 @@ __all__ = [
     "RingLog",
     "Span",
     "Tracer",
+    "block_until_ready",
     "configure",
+    "device_get",
+    "device_put",
     "format_trace",
     "get_bus",
     "get_registry",

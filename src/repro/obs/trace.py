@@ -21,6 +21,24 @@ SS records are derived post-hoc from ``SSResult.alive_trace``, and
 per-round wall times are model-apportioned estimates of the measured total
 (``wall_est``), not in-loop measurements.
 
+With tracing on, every lexical span (:func:`span`) also enters
+``jax.profiler.TraceAnnotation(name)``, so under a profiler the spans sit
+on its host plane, on the device trace's clock.  Retroactive spans
+(:meth:`Tracer.record`: ``queue.wait``, ``jax.compile``) stay on the
+program's clock only.  ``jax.compile`` spans come from a
+``jax.monitoring`` backend-compile listener installed the first time
+tracing is turned on; each is recorded under the span open on the
+compiling thread.
+
+Host↔device boundary counters: the served path moves data through
+:func:`device_put`, :func:`device_get` and :func:`block_until_ready`.
+Each makes exactly the transfer or wait its plain JAX namesake makes; with
+tracing on it also adds to the innermost open span's ``syncs`` (device
+reads and waits), ``h2d_bytes`` (host bytes copied to the device) and
+``sites`` (calls per named site).  Reads and waits that exist only for
+the tracer itself pass ``instrumentation=True`` and count under
+``obs_syncs`` instead.
+
 Span trees are assembled per request: a span belongs to request ``i`` when
 its ``trace_id == f"req-{i}"`` or its ``request_ids`` attr contains ``i``
 (a chunk span is shared by its batch mates).  :func:`format_trace` renders
@@ -32,11 +50,16 @@ from __future__ import annotations
 
 import contextlib
 import contextvars
+import functools
 import itertools
 import os
 import threading
 import time
 from typing import Any, Iterator
+
+import jax
+import numpy as np
+from jax import profiler as _profiler
 
 from repro.obs.events import RingLog
 
@@ -49,13 +72,15 @@ class Span:
 
     __slots__ = (
         "span_id", "parent_id", "trace_id", "name", "t0", "t1", "status",
-        "attrs",
+        "attrs", "parent",
     )
 
     def __init__(self, span_id: int, parent_id: int | None, trace_id: str,
-                 name: str, t0: float, attrs: dict):
+                 name: str, t0: float, attrs: dict,
+                 parent: "Span | None" = None):
         self.span_id = span_id
         self.parent_id = parent_id
+        self.parent = parent
         self.trace_id = trace_id
         self.name = name
         self.t0 = t0
@@ -69,6 +94,13 @@ class Span:
             - self.t0
 
     def set(self, **attrs: Any) -> "Span":
+        self.attrs.update(attrs)
+        return self
+
+    def fail(self, **attrs: Any) -> "Span":
+        """Mark the span failed (``status="error"``) without raising through
+        it, e.g. an admission whose error is captured on its ticket."""
+        self.status = "error"
         self.attrs.update(attrs)
         return self
 
@@ -106,6 +138,9 @@ class _NoopSpan:
     wall_s = 0.0
 
     def set(self, **attrs: Any) -> "_NoopSpan":
+        return self
+
+    def fail(self, **attrs: Any) -> "_NoopSpan":
         return self
 
 
@@ -151,7 +186,7 @@ class Tracer:
             span_id=sid,
             parent_id=None if parent is None else parent.span_id,
             trace_id=trace_id, name=name, t0=time.perf_counter(),
-            attrs=dict(attrs),
+            attrs=dict(attrs), parent=parent,
         )
 
     def finish(self, sp: Span, status: str = "ok") -> None:
@@ -161,13 +196,15 @@ class Tracer:
         if sp.t1 is None:
             sp.t1 = time.perf_counter()
         sp.status = status
+        sp.parent = None        # only open spans need their parent object
         self._ring.append(sp)
 
     def span(self, name: str, *, trace_id: str | None = None,
              **attrs: Any):
         """Context manager: open a child of the currently-active span, make
         it current for the body, record it on exit (``status="error"`` when
-        the body raises)."""
+        the body raises or calls :meth:`Span.fail`).  The body also runs
+        inside ``jax.profiler.TraceAnnotation(name)``."""
         if not self.enabled:
             return _noop_cm()
         return self._span_cm(name, trace_id, attrs)
@@ -178,13 +215,14 @@ class Tracer:
         sp = self.start_span(name, trace_id=trace_id, **attrs)
         token = self._current.set(sp)
         try:
-            yield sp
+            with _profiler.TraceAnnotation(name):
+                yield sp
         except BaseException:
             self.finish(sp, status="error")
             raise
         finally:
             self._current.reset(token)
-        self.finish(sp)
+        self.finish(sp, sp.status)
 
     def record(self, name: str, t0: float, t1: float, *,
                trace_id: str | None = None, parent: Span | None = None,
@@ -197,6 +235,7 @@ class Tracer:
         sp = self.start_span(name, trace_id=trace_id, parent=parent, **attrs)
         sp.t0, sp.t1 = t0, t1
         sp.status = status
+        sp.parent = None
         self._ring.append(sp)
 
     def current_span(self) -> Span | None:
@@ -260,17 +299,53 @@ def get_tracer() -> Tracer:
     return _tracer
 
 
+BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_compile_listener_installed = False
+
+
+def _on_jax_event(event: str, duration: float, **kwargs: Any) -> None:
+    """``jax.monitoring`` duration listener: each backend compile (a
+    persistent-cache load included) becomes a retroactive ``jax.compile``
+    span under the span open on the compiling thread, with the ``lane`` of
+    that span or of its nearest open ancestor that has one."""
+    if event != BACKEND_COMPILE_EVENT or not _tracer.enabled:
+        return
+    parent = _tracer.current_span()
+    lane, sp = None, parent
+    while sp is not None and lane is None:
+        lane, sp = sp.attrs.get("lane"), sp.parent
+    t1 = time.perf_counter()
+    _tracer.record(
+        "jax.compile", t1 - duration, t1, parent=parent,
+        fun_name=kwargs.get("fun_name"), lane=lane,
+    )
+
+
+def _install_compile_listener() -> None:
+    global _compile_listener_installed
+    if not _compile_listener_installed:
+        jax.monitoring.register_event_duration_secs_listener(_on_jax_event)
+        _compile_listener_installed = True
+
+
 def configure(*, trace: bool | None = None,
               capacity: int | None = None) -> Tracer:
     """Enable/disable tracing and/or resize the span ring.  Resizing drops
     recorded spans (the ring is rebuilt); the enable flag is cheap to flip
-    at any time."""
+    at any time.  Turning tracing on installs the ``jax.compile`` listener
+    (once per process; it does nothing while tracing is off)."""
     global _tracer
     if capacity is not None and capacity != _tracer._ring.capacity:
         _tracer = Tracer(capacity=capacity, enabled=_tracer.enabled)
     if trace is not None:
         _tracer.enabled = bool(trace)
+    if _tracer.enabled:
+        _install_compile_listener()
     return _tracer
+
+
+if _tracer.enabled:     # REPRO_TRACE=1 at import
+    _install_compile_listener()
 
 
 def trace_enabled() -> bool:
@@ -281,6 +356,70 @@ def span(name: str, *, trace_id: str | None = None, **attrs: Any):
     """Module-level convenience: a span on the global tracer (no-op context
     manager when tracing is disabled)."""
     return _tracer.span(name, trace_id=trace_id, **attrs)
+
+
+def named_scope(name: str):
+    """Decorator: run the function's body under ``jax.named_scope(name)``,
+    so the ops it traces carry ``name`` in their metadata and a device
+    trace can map them to a layer.  ``wraps`` keeps the function's name,
+    hence the name of the module ``jax.jit`` compiles from it."""
+    def deco(f):
+        @functools.wraps(f)
+        def scoped(*args, **kwargs):
+            with jax.named_scope(name):
+                return f(*args, **kwargs)
+        return scoped
+    return deco
+
+
+# ------------------------------------------------ host<->device counters ----
+
+def _count(attr: str, site: str, amount: int) -> None:
+    """Add ``amount`` to the innermost open span's ``attr`` and one call
+    to its ``sites[site]`` (spans are thread-local, so only the owning
+    thread mutates them)."""
+    sp = _tracer.current_span()
+    if sp is None:
+        return
+    sp.attrs[attr] = sp.attrs.get(attr, 0) + amount
+    sites = sp.attrs.setdefault("sites", {})
+    sites[site] = sites.get(site, 0) + 1
+
+
+def _host_nbytes(leaf: Any) -> int:
+    """Bytes a ``device_put`` of ``leaf`` copies to the device: 0 for an
+    array already there, else its size at JAX's canonical dtype."""
+    if isinstance(leaf, jax.Array):
+        return 0
+    a = np.asarray(leaf)
+    return a.size * np.dtype(jax.dtypes.canonicalize_dtype(a.dtype)).itemsize
+
+
+def device_put(site: str, x: Any) -> Any:
+    """``jax.device_put(x)``, counted: with tracing on, the host bytes it
+    copies go to the innermost open span's ``h2d_bytes``."""
+    if _tracer.enabled:
+        _count("h2d_bytes", site, sum(
+            _host_nbytes(leaf) for leaf in jax.tree_util.tree_leaves(x)))
+    return jax.device_put(x)
+
+
+def device_get(site: str, x: Any, *, instrumentation: bool = False) -> Any:
+    """``jax.device_get(x)``, counted: with tracing on, one ``syncs`` (or
+    ``obs_syncs`` for a read only the tracer makes) on the innermost open
+    span."""
+    if _tracer.enabled:
+        _count("obs_syncs" if instrumentation else "syncs", site, 1)
+    return jax.device_get(x)
+
+
+def block_until_ready(site: str, x: Any, *,
+                      instrumentation: bool = False) -> Any:
+    """``jax.block_until_ready(x)``, counted like :func:`device_get`: a wait
+    for the device is a host sync even when nothing is copied."""
+    if _tracer.enabled:
+        _count("obs_syncs" if instrumentation else "syncs", site, 1)
+    return jax.block_until_ready(x)
 
 
 # ------------------------------------------------------------- rendering ----

@@ -366,10 +366,11 @@ class SummarizeResponse:
 
 def build_batch_objective(requests: list[SummarizeRequest], n_pad: int | None):
     """Stack one lane's payloads into a batched objective (+ alive mask when
-    ground-set padding is active).  All requests must share a lane."""
+    ground-set padding is active).  All requests must share a lane.  Each
+    payload is copied to the device through :func:`repro.obs.device_put`."""
     req0 = requests[0]
     if req0.objective == "coverage":
-        Ws = [jnp.asarray(r.features) for r in requests]
+        Ws = [obs.device_put("build.payload", r.features) for r in requests]
         if n_pad is not None:
             Ws = [
                 jnp.zeros((n_pad, W.shape[1]), W.dtype).at[: W.shape[0]].set(W)
@@ -378,7 +379,7 @@ def build_batch_objective(requests: list[SummarizeRequest], n_pad: int | None):
         fn = FeatureCoverage(W=jnp.stack(Ws), phi=req0.phi)
     elif req0.objective == "fl":
         if req0.sim is not None:
-            sims = [jnp.asarray(r.sim) for r in requests]
+            sims = [obs.device_put("build.payload", r.sim) for r in requests]
             if n_pad is not None:
                 sims = [
                     jnp.zeros((n_pad, n_pad), s.dtype)
@@ -387,7 +388,8 @@ def build_batch_objective(requests: list[SummarizeRequest], n_pad: int | None):
                 ]
             sim_b = jnp.stack(sims)
         else:
-            Xs = [jnp.asarray(r.features) for r in requests]
+            Xs = [obs.device_put("build.payload", r.features)
+                  for r in requests]
             if n_pad is not None:
                 Xs = [
                     jnp.zeros((n_pad, X.shape[1]), X.dtype)
@@ -509,12 +511,14 @@ class Ticket:
     watchdog abandons a hung attempt and the recovery path re-runs the
     chunk, whichever attempt finishes first owns the ticket — the loser's
     late results are discarded, so a ticket can never be resolved twice or
-    flap between a response and an error.
+    flap between a response and an error.  ``settled_t`` is the
+    ``time.perf_counter()`` reading the winning settle took (None while
+    unresolved).
     """
 
     __slots__ = (
         "index", "_submit_t", "_deadline_t", "_event", "_response", "_error",
-        "_steps", "_lock", "_state",
+        "_steps", "_lock", "_state", "settled_t",
     )
 
     def __init__(self, index: int, submit_t: float,
@@ -528,6 +532,7 @@ class Ticket:
         self._steps: list[tuple[int, float]] = []
         self._lock = threading.Lock()
         self._state = "queued"      # queued | executing | done | failed
+        self.settled_t: float | None = None   # perf_counter at settle
 
     def done(self) -> bool:
         """True once the ticket holds a response or a captured error."""
@@ -577,6 +582,7 @@ class Ticket:
         with self._lock:
             if self._event.is_set():
                 return False
+            self.settled_t = time.perf_counter()
             self._response = response
             self._error = error
             self._state = "done" if error is None else "failed"
@@ -717,6 +723,12 @@ class SummarizeService:
         queue backpressure — fail the returned ticket immediately instead
         of raising, so one bad request never blocks its batch mates (and
         never corrupts the compiled chunk it would have ridden in)."""
+        tid = f"req-{self._n_submitted}" if obs.trace_enabled() else None
+        with obs.span("request.admit", trace_id=tid) as sp:
+            return self._admit(request, sp)
+
+    def _admit(self, request: SummarizeRequest, sp) -> Ticket:
+        """The body of :meth:`submit`, inside its ``request.admit`` span."""
         now = time.perf_counter()
         deadline_t = (
             None if request.deadline_s is None else now + request.deadline_s
@@ -725,11 +737,7 @@ class SummarizeService:
         self._n_submitted += 1
         try:
             if self._killed:
-                raise ServiceRestarted(
-                    "the service crashed (injected crash fault); in-flight "
-                    "tickets were settled with ServiceRestarted and new "
-                    "submissions are rejected — construct a new service"
-                )
+                raise self._dead()
             lane = self._lane(request)
             if request.k < 1:
                 raise ValueError(f"k must be >= 1; got k={request.k}")
@@ -737,7 +745,12 @@ class SummarizeService:
                 payload = (
                     request.sim if request.sim is not None else request.features
                 )
-                if not bool(jnp.all(jnp.isfinite(jnp.asarray(payload)))):
+                with obs.span("request.validate"):
+                    x = obs.device_put("admit.payload", payload)
+                    finite = obs.device_get(
+                        "admit.finite", jnp.all(jnp.isfinite(x))
+                    )
+                if not bool(finite):
                     raise ValueError(
                         "payload contains non-finite values (NaN/Inf); "
                         "rejected at admission (RunConfig.validate_payloads)"
@@ -748,6 +761,10 @@ class SummarizeService:
                     "admission"
                 )
             with self._cond:
+                if self._killed:
+                    # A crash landed while this request was being validated:
+                    # its drain has passed, so the request must not queue.
+                    raise self._dead()
                 cap = self.config.max_pending
                 if cap is not None and self._pending >= cap:
                     raise ServiceOverloaded(
@@ -768,25 +785,22 @@ class SummarizeService:
                 "repro_service_requests_total", "admitted requests by outcome",
                 labels=("outcome",),
             ).inc(outcome="rejected")
-            tr = obs.get_tracer()
-            if tr.enabled:
-                tr.record(
-                    "request.admit", now, time.perf_counter(),
-                    trace_id=f"req-{ticket.index}", status="error",
-                    error=type(e).__name__,
-                )
+            sp.fail(error=type(e).__name__)
             return ticket
         obs.get_registry().counter(
             "repro_service_requests_total", "admitted requests by outcome",
             labels=("outcome",),
         ).inc(outcome="admitted")
-        tr = obs.get_tracer()
-        if tr.enabled:
-            tr.record(
-                "request.admit", now, time.perf_counter(),
-                trace_id=f"req-{ticket.index}", lane=_lane_label(lane),
-            )
+        sp.set(lane=_lane_label(lane))
         return ticket
+
+    @staticmethod
+    def _dead() -> ServiceRestarted:
+        return ServiceRestarted(
+            "the service crashed (injected crash fault); in-flight "
+            "tickets were settled with ServiceRestarted and new "
+            "submissions are rejected — construct a new service"
+        )
 
     def _lane(self, req: SummarizeRequest) -> tuple:
         payload = req.sim if req.sim is not None else req.features
@@ -1257,6 +1271,7 @@ class SummarizeService:
                 it.ticket._steps.clear()    # a retried attempt restarts it
 
             def on_step(step, v, g, ok):
+                v, g, ok = obs.device_get("greedy.step", (v, g, ok))
                 for i, it in enumerate(items):
                     if bool(ok[i]):
                         it.ticket._steps.append((int(v[i]), float(g[i])))
@@ -1281,8 +1296,9 @@ class SummarizeService:
             trigger=trigger, bucket=bucket, batch=n_real,
             degraded=0 if deg is None else deg["level"],
         ):
-            fn, alive = build_batch_objective(padded, n_pad)
-            keys = jnp.stack([r.prng_key() for r in padded])
+            with tr.span("chunk.build"):
+                fn, alive = build_batch_objective(padded, n_pad)
+                keys = jnp.stack([r.prng_key() for r in padded])
             res, ss = summarize_batch(
                 fn, k, keys,
                 r=cfg.r if deg is None else deg["r"],
@@ -1292,21 +1308,42 @@ class SummarizeService:
                 selector="greedy" if deg is None else deg["selector"],
                 eps=cfg.eps,
             )
-            jax.block_until_ready(res.value)
+            obs.block_until_ready("chunk.ready", res.value)
             if fault is not None and fault.kind == "malformed":
                 res = res._replace(gains=jnp.full_like(res.gains, jnp.nan))
-            finite = bool(
+            finite = bool(obs.device_get(
+                "chunk.finite",
                 jnp.all(jnp.isfinite(res.gains[:n_real]))
-                & jnp.all(jnp.isfinite(res.value[:n_real]))
-            )
+                & jnp.all(jnp.isfinite(res.value[:n_real])),
+            ))
             if not finite:
                 raise MalformedResult(
                     f"non-finite gains/value in chunk results "
                     f"({stage}/{be.name})"
                 )
             t_end = time.perf_counter()
-            exec_s = t_end - t_start
 
+        with tr.span(
+            "chunk.respond", trace_id=f"req-{items[0].ticket.index}",
+            request_ids=tuple(it.ticket.index for it in items), batch=n_real,
+        ):
+            self._respond(
+                lane, items, res, ss, trigger, be=be, stage=stage, deg=deg,
+                recovery=recovery, bucket=bucket, t_start=t_start,
+                t_end=t_end,
+            )
+
+    def _respond(
+        self, lane: tuple, items: list[_QueueItem], res: GreedyResult,
+        ss: SSResult | None, trigger: str, *, be, stage: str,
+        deg: dict | None, recovery: dict | None, bucket: int,
+        t_start: float, t_end: float,
+    ) -> None:
+        """Response assembly and accounting of one executed chunk: read
+        each query's fields, settle its ticket, then update the stats, the
+        execution estimate and the registry (the ``chunk.respond`` span)."""
+        n_real = len(items)
+        exec_s = t_end - t_start
         vp_sizes = (
             None if ss is None else jnp.sum(ss.vprime, axis=1)
         )
@@ -1318,10 +1355,13 @@ class SummarizeService:
             responses.append(SummarizeResponse(
                 selected=res.selected[i],
                 gains=res.gains[i],
-                value=float(res.value[i]),
-                vprime_size=None if ss is None else int(vp_sizes[i]),
-                eps_hat=None if ss is None else float(ss.eps_hat[i]),
-                rounds=None if ss is None else int(ss.rounds[i]),
+                value=float(obs.device_get("respond.value", res.value[i])),
+                vprime_size=None if ss is None else int(
+                    obs.device_get("respond.vprime_size", vp_sizes[i])),
+                eps_hat=None if ss is None else float(
+                    obs.device_get("respond.eps_hat", ss.eps_hat[i])),
+                rounds=None if ss is None else int(
+                    obs.device_get("respond.rounds", ss.rounds[i])),
                 lane=lane,
                 batch_size=n_real,
                 batch_bucket=bucket,

@@ -185,6 +185,26 @@ class TestTracer:
         finally:
             obs.configure(capacity=obs.trace.DEFAULT_CAPACITY)
 
+    def test_transfer_helpers_count_on_the_innermost_span(self):
+        host = np.ones((3, 4), np.float64)      # copied as float32
+        dev = jnp.ones((5,))
+        assert obs.device_put("x", host).dtype == jnp.float32   # off: no span
+        obs.configure(trace=True)
+        tr = obs.get_tracer()
+        with tr.span("outer") as outer:
+            with tr.span("inner") as inner:
+                obs.device_put("put", (host, dev))   # dev is already there
+                got = obs.device_get("get", dev)
+                obs.block_until_ready("wait", dev)
+                obs.device_get("own", dev, instrumentation=True)
+            obs.device_get("get", dev)
+        assert isinstance(got, np.ndarray) and (got == 1).all()
+        assert inner.attrs["h2d_bytes"] == 3 * 4 * 4
+        assert inner.attrs["syncs"] == 2 and inner.attrs["obs_syncs"] == 1
+        assert inner.attrs["sites"] == {"put": 1, "get": 1, "wait": 1,
+                                        "own": 1}
+        assert outer.attrs == {"syncs": 1, "sites": {"get": 1}}
+
     def test_format_trace_renders_tree(self):
         obs.configure(trace=True)
         tr = obs.get_tracer()
@@ -460,6 +480,195 @@ class TestServiceTelemetry:
                     <= st["queue_delay_s_max"] + 1e-12
                 )
         assert snaps[-1]["queries"] == len(queries)
+
+
+# ------------------------------------------------ host boundaries ----
+
+def host_queries(num=2, n=128, F=32, k=4, seed=3):
+    """Requests whose payloads are host (numpy) arrays, as a client sends
+    them: admission and the chunk build each copy them to the device."""
+    return [
+        SummarizeRequest(
+            k=k, key=jax.random.PRNGKey(seed * 100 + i),
+            features=news_day(seed * 100 + i, n, F),
+        )
+        for i in range(num)
+    ]
+
+
+def payload_bytes(req):
+    return req.features.shape[0] * req.features.shape[1] * 4   # float32
+
+
+class TestHostBoundaries:
+    """Spans at each host boundary of a served query, the host<->device
+    traffic counted on them, and the bridge to the profiler's clock."""
+
+    def test_chunk_tree_holds_each_host_boundary(self):
+        obs.configure(trace=True)
+        reqs = host_queries(num=2)
+        SummarizeService(RunConfig(max_batch=2)).run(reqs)
+        tr = obs.get_tracer()
+        tree = tr.spans_for_request(0)
+        by_name = {s.name: s for s in tree}
+        for name in ("request.admit", "request.validate", "queue.wait",
+                     "chunk.exec", "chunk.build", "ss.sparsify_batched",
+                     "greedy.plan", "greedy.select_batched", "chunk.respond"):
+            assert name in by_name, name
+        chunk = by_name["chunk.exec"]
+        build, plan = by_name["chunk.build"], by_name["greedy.plan"]
+        select, respond = by_name["greedy.select_batched"], by_name["chunk.respond"]
+        assert by_name["request.validate"].parent_id == \
+            by_name["request.admit"].span_id
+        assert build.parent_id == chunk.span_id
+        assert plan.parent_id == chunk.span_id and plan.t1 <= select.t0
+        # chunk.respond follows chunk.exec and names the same chunk
+        assert respond.t0 >= chunk.t1
+        assert respond.trace_id == chunk.trace_id
+        assert respond.attrs["request_ids"] == chunk.attrs["request_ids"]
+        assert respond.attrs["batch"] == chunk.attrs["batch"] == 2
+
+        # bytes: each payload once at admission, once at the build
+        sizes = [payload_bytes(r) for r in reqs]
+        validates = tr.spans(name="request.validate")
+        assert sorted(s.attrs["h2d_bytes"] for s in validates) == sorted(sizes)
+        assert build.attrs["h2d_bytes"] == sum(sizes)
+        assert sum(s.attrs.get("h2d_bytes", 0) for s in tr.spans()) == \
+            2 * sum(sizes)
+
+        # syncs: admission's check, the live-count read, the ready wait and
+        # the finiteness check, four response reads per query
+        assert [s.attrs["syncs"] for s in validates] == [1, 1]
+        assert plan.attrs["syncs"] == 1
+        assert chunk.attrs["syncs"] == 2
+        assert respond.attrs["syncs"] == 4 * len(reqs)
+        assert respond.attrs["sites"]["respond.value"] == len(reqs)
+        # the tracer's own reads are kept apart: one wait and one read each
+        for name in ("ss.sparsify_batched", "greedy.select_batched"):
+            assert by_name[name].attrs["obs_syncs"] == 2
+            assert "syncs" not in by_name[name].attrs
+
+    def test_rejected_admission_marks_its_span_failed(self):
+        obs.configure(trace=True)
+        bad = host_queries(num=1)[0]
+        bad.features[0, 0] = np.nan
+        ticket = SummarizeService(RunConfig()).submit(bad)
+        assert isinstance(ticket.exception(timeout=0), ValueError)
+        (admit,) = obs.get_tracer().spans(name="request.admit")
+        assert admit.status == "error" and admit.attrs["error"] == "ValueError"
+        assert "lane" not in admit.attrs
+        (check,) = obs.get_tracer().spans(name="request.validate")
+        assert check.parent_id == admit.span_id and check.attrs["syncs"] == 1
+
+    @pytest.mark.parametrize("trace", [False, True])
+    @pytest.mark.parametrize("scheduler", ["sync", "async"])
+    def test_no_device_read_bypasses_the_helpers(self, trace, scheduler,
+                                                 monkeypatch):
+        """A service round with implicit device-to-host transfers
+        disallowed, in every thread.  JAX's guard does not fire on the CPU
+        backend, whose arrays are read in place, so the test also refuses
+        any read of an array's host value outside an explicit
+        ``jax.device_get``, which is what the guard does on a TPU.
+
+        The host-to-device direction cannot be guarded so: Python scalars
+        trip that guard (an int index into a device array, the fill of
+        ``jnp.zeros``), and ``jnp.asarray`` of a host array counts as
+        explicit.  The payload copies are held by the byte count of
+        ``test_chunk_tree_holds_each_host_boundary`` instead."""
+        from jax._src import array as jax_array
+        from jax._src.lib import guard_lib
+
+        reqs = host_queries(num=3)
+        want = SummarizeService(RunConfig(max_batch=2)).run(reqs)
+        obs.configure(trace=trace)
+        cfg = RunConfig(max_batch=2, scheduler=scheduler, max_wait_s=0.01,
+                        stream_steps=True)
+        host_value = jax_array.ArrayImpl._value
+
+        def guarded(arr):
+            if not guard_lib.thread_local_state().explicit_device_get:
+                raise AssertionError("implicit device-to-host read")
+            return host_value.fget(arr)
+
+        jax.config.update("jax_transfer_guard_device_to_host", "disallow")
+        monkeypatch.setattr(jax_array.ArrayImpl, "_value", property(guarded))
+        try:
+            with SummarizeService(cfg) as svc:
+                got = svc.run(reqs)
+        finally:
+            monkeypatch.undo()
+            jax.config.update("jax_transfer_guard_device_to_host", "allow")
+        for a, b in zip(want, got):
+            assert (np.asarray(a.selected) == np.asarray(b.selected)).all()
+            assert (np.asarray(a.gains) == np.asarray(b.gains)).all()
+            assert a.value == b.value and a.vprime_size == b.vprime_size
+
+    def test_each_lexical_span_enters_one_trace_annotation(self, monkeypatch):
+        entered = []
+
+        class Annotation:
+            def __init__(self, name):
+                self.name = name
+
+            def __enter__(self):
+                entered.append(self.name)
+                return self
+
+            def __exit__(self, *exc):
+                return False
+
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
+        SummarizeService(RunConfig(max_batch=2)).run(host_queries(num=2))
+        assert entered == []                    # tracing off: never entered
+        obs.configure(trace=True)
+        SummarizeService(RunConfig(max_batch=2)).run(host_queries(num=2))
+        lexical = [s.name for s in obs.get_tracer().spans()
+                   if s.name not in ("queue.wait", "jax.compile")]
+        assert sorted(entered) == sorted(lexical)
+        assert {"request.admit", "chunk.exec", "chunk.respond"} <= set(entered)
+
+    def test_a_new_lane_shape_compiles_under_its_chunk(self):
+        obs.configure(trace=True)
+        # a shape no other test serves, so its programs compile here
+        SummarizeService(RunConfig(max_batch=1)).run(
+            host_queries(num=1, n=75, F=19)
+        )
+        tr = obs.get_tracer()
+        by_id = {s.span_id: s for s in tr.spans()}
+        (chunk,) = tr.spans(name="chunk.exec")
+
+        def under_chunk(s):
+            while s is not None and s.parent_id is not None:
+                if s.parent_id == chunk.span_id:
+                    return True
+                s = by_id.get(s.parent_id)
+            return False
+
+        compiles = [s for s in tr.spans(name="jax.compile") if under_chunk(s)]
+        assert compiles
+        assert {s.attrs["lane"] for s in compiles} == {chunk.attrs["lane"]}
+        assert any("sparsify_batched" in s.attrs["fun_name"] for s in compiles)
+        assert all(s.t1 >= s.t0 for s in compiles)
+
+    def test_settled_t_lies_between_submit_and_the_waiters_stamp(self):
+        stamps = {}
+
+        def wait(ticket):
+            ticket.result(timeout=120)
+            stamps[ticket.index] = time.perf_counter()
+
+        cfg = RunConfig(scheduler="async", max_batch=2, max_wait_s=0.01)
+        with SummarizeService(cfg) as svc:
+            tickets = [svc.submit(r) for r in host_queries(num=3)]
+            waiters = [threading.Thread(target=wait, args=(t,))
+                       for t in tickets]
+            for w in waiters:
+                w.start()
+            for w in waiters:
+                w.join(timeout=120)
+            assert not any(w.is_alive() for w in waiters)
+        for t in tickets:
+            assert t._submit_t <= t.settled_t <= stamps[t.index]
 
 
 # ----------------------------------------------------------------- ewma ----
