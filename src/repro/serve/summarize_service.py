@@ -100,6 +100,7 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro import obs
 from repro.core import (
@@ -343,10 +344,13 @@ class SummarizeResponse:
     ``forced``).  ``recovery`` is None for a first-attempt success, else
     ``{"retries", "stage", "backends", "isolated"}`` describing the
     recovery path that served it.
+
+    Every field is on the host: ``selected`` and ``gains`` are numpy rows of
+    the chunk's one device-to-host read, the scalars Python numbers.
     """
 
-    selected: Array                 # (k,) int32 ground indices
-    gains: Array                    # (k,) marginal gains
+    selected: np.ndarray            # (k,) int32 ground indices, on the host
+    gains: np.ndarray               # (k,) float32 marginal gains, on the host
     value: float                    # f(S)
     vprime_size: int | None         # |V'| after SS (None when use_ss=False)
     eps_hat: float | None           # SS certificate (None when use_ss=False)
@@ -1308,15 +1312,21 @@ class SummarizeService:
                 selector="greedy" if deg is None else deg["selector"],
                 eps=cfg.eps,
             )
-            obs.block_until_ready("chunk.ready", res.value)
             if fault is not None and fault.kind == "malformed":
                 res = res._replace(gains=jnp.full_like(res.gains, jnp.nan))
-            finite = bool(obs.device_get(
-                "chunk.finite",
-                jnp.all(jnp.isfinite(res.gains[:n_real]))
-                & jnp.all(jnp.isfinite(res.value[:n_real])),
-            ))
-            if not finite:
+            # One device->host read of everything the tickets need: every
+            # leaf's copy starts before the wait, so this is the chunk's
+            # only wait too.
+            fields = {
+                "value": res.value, "gains": res.gains,
+                "selected": res.selected,
+            }
+            if ss is not None:
+                fields.update(
+                    vprime=ss.vprime, eps_hat=ss.eps_hat, rounds=ss.rounds)
+            out = obs.device_get("chunk.readout", fields)
+            if not (np.isfinite(out["value"][:n_real]).all()
+                    and np.isfinite(out["gains"][:n_real]).all()):
                 raise MalformedResult(
                     f"non-finite gains/value in chunk results "
                     f"({stage}/{be.name})"
@@ -1328,40 +1338,39 @@ class SummarizeService:
             request_ids=tuple(it.ticket.index for it in items), batch=n_real,
         ):
             self._respond(
-                lane, items, res, ss, trigger, be=be, stage=stage, deg=deg,
+                lane, items, out, trigger, be=be, stage=stage, deg=deg,
                 recovery=recovery, bucket=bucket, t_start=t_start,
                 t_end=t_end,
             )
 
     def _respond(
-        self, lane: tuple, items: list[_QueueItem], res: GreedyResult,
-        ss: SSResult | None, trigger: str, *, be, stage: str,
-        deg: dict | None, recovery: dict | None, bucket: int,
-        t_start: float, t_end: float,
+        self, lane: tuple, items: list[_QueueItem], out: dict, trigger: str,
+        *, be, stage: str, deg: dict | None, recovery: dict | None,
+        bucket: int, t_start: float, t_end: float,
     ) -> None:
-        """Response assembly and accounting of one executed chunk: read
-        each query's fields, settle its ticket, then update the stats, the
-        execution estimate and the registry (the ``chunk.respond`` span)."""
+        """Response assembly and accounting of one executed chunk: build
+        each query's response from the chunk's host readout ``out`` (numpy
+        ``value``, ``gains``, ``selected``, and ``vprime`` / ``eps_hat`` /
+        ``rounds`` when SS ran; one row a query), settle its ticket, then
+        update the stats, the execution estimate and the registry (the
+        ``chunk.respond`` span).  Nothing here touches the device."""
         n_real = len(items)
         exec_s = t_end - t_start
-        vp_sizes = (
-            None if ss is None else jnp.sum(ss.vprime, axis=1)
-        )
+        use_ss = "vprime" in out
+        if use_ss:
+            vp_sizes = out["vprime"].sum(axis=1)
         responses = []
         for i, it in enumerate(items):
             deadline_missed = (
                 None if it.deadline_t is None else t_end > it.deadline_t
             )
             responses.append(SummarizeResponse(
-                selected=res.selected[i],
-                gains=res.gains[i],
-                value=float(obs.device_get("respond.value", res.value[i])),
-                vprime_size=None if ss is None else int(
-                    obs.device_get("respond.vprime_size", vp_sizes[i])),
-                eps_hat=None if ss is None else float(
-                    obs.device_get("respond.eps_hat", ss.eps_hat[i])),
-                rounds=None if ss is None else int(
-                    obs.device_get("respond.rounds", ss.rounds[i])),
+                selected=out["selected"][i],
+                gains=out["gains"][i],
+                value=float(out["value"][i]),
+                vprime_size=int(vp_sizes[i]) if use_ss else None,
+                eps_hat=float(out["eps_hat"][i]) if use_ss else None,
+                rounds=int(out["rounds"][i]) if use_ss else None,
                 lane=lane,
                 batch_size=n_real,
                 batch_bucket=bucket,

@@ -536,17 +536,70 @@ class TestHostBoundaries:
         assert sum(s.attrs.get("h2d_bytes", 0) for s in tr.spans()) == \
             2 * sum(sizes)
 
-        # syncs: admission's check, the live-count read, the ready wait and
-        # the finiteness check, four response reads per query
+        # syncs: admission's check, the live-count read, and the chunk's
+        # one readout; response assembly reads nothing from the device
         assert [s.attrs["syncs"] for s in validates] == [1, 1]
         assert plan.attrs["syncs"] == 1
-        assert chunk.attrs["syncs"] == 2
-        assert respond.attrs["syncs"] == 4 * len(reqs)
-        assert respond.attrs["sites"]["respond.value"] == len(reqs)
+        assert chunk.attrs["syncs"] == 1
+        assert chunk.attrs["sites"] == {"chunk.readout": 1}
+        assert "syncs" not in respond.attrs
+        assert "sites" not in respond.attrs
         # the tracer's own reads are kept apart: one wait and one read each
         for name in ("ss.sparsify_batched", "greedy.select_batched"):
             assert by_name[name].attrs["obs_syncs"] == 2
             assert "syncs" not in by_name[name].attrs
+
+    @pytest.mark.parametrize("num", [1, 2, 3])
+    def test_a_served_chunk_reads_the_device_once_after_selection(
+        self, num, monkeypatch
+    ):
+        """Whatever the chunk's size (3 is padded to a bucket of 4), one
+        ``jax.device_get`` follows selection, and the responses are host
+        rows of ``summarize_batch``'s output."""
+        from repro.serve import summarize_service as svc_mod
+
+        obs.configure(trace=True)
+        real_get, real_batch = jax.device_get, svc_mod.summarize_batch
+        outputs, reads = [], []
+
+        def counting_get(x):
+            if outputs:
+                reads.append(x)
+            return real_get(x)
+
+        def recording_batch(*a, **kw):
+            out = real_batch(*a, **kw)
+            outputs.append(out)
+            return out
+
+        monkeypatch.setattr(jax, "device_get", counting_get)
+        monkeypatch.setattr(svc_mod, "summarize_batch", recording_batch)
+        got = SummarizeService(RunConfig(max_batch=4)).run(
+            host_queries(num=num))
+        monkeypatch.undo()
+
+        assert len(outputs) == 1 and len(reads) == 1
+        tr = obs.get_tracer()
+        (chunk,) = tr.spans(name="chunk.exec")
+        (respond,) = tr.spans(name="chunk.respond")
+        assert chunk.attrs["batch"] == num
+        assert chunk.attrs["sites"] == {"chunk.readout": 1}
+        assert "syncs" not in respond.attrs
+
+        (res, ss), = outputs
+        sel, gains = np.asarray(res.selected), np.asarray(res.gains)
+        vprime = np.asarray(ss.vprime)
+        for i, resp in enumerate(got):
+            assert isinstance(resp.selected, np.ndarray)
+            assert isinstance(resp.gains, np.ndarray)
+            assert resp.selected.dtype == sel.dtype
+            assert resp.gains.dtype == gains.dtype
+            assert (resp.selected == sel[i]).all()
+            assert (resp.gains == gains[i]).all()
+            assert resp.value == float(np.asarray(res.value)[i])
+            assert resp.vprime_size == int(vprime[i].sum())
+            assert resp.eps_hat == float(np.asarray(ss.eps_hat)[i])
+            assert resp.rounds == int(np.asarray(ss.rounds)[i])
 
     def test_rejected_admission_marks_its_span_failed(self):
         obs.configure(trace=True)

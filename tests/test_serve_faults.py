@@ -139,6 +139,44 @@ def test_malformed_result_detected_and_retried():
     assert svc.stats()["retries"] == 1
 
 
+@pytest.mark.parametrize("bad_row,caught", [(1, True), (3, False)])
+def test_finiteness_check_covers_the_real_rows_only(
+    bad_row, caught, monkeypatch
+):
+    """Three queries run in a bucket of four.  A non-finite gain in one
+    real row fails the attempt before any ticket settles, so every query,
+    its finite batch mates too, is served by the retry; one in the padding
+    row is no fault."""
+    from repro.serve import summarize_service as svc_mod
+
+    reqs = [req(i) for i in range(3)]
+    ref = SummarizeService(RunConfig(max_batch=4)).run(list(reqs))
+    real = svc_mod.summarize_batch
+    buckets = []
+
+    def poisoned(*a, **kw):
+        res, ss = real(*a, **kw)
+        buckets.append(res.gains.shape[0])
+        if len(buckets) == 1:
+            res = res._replace(gains=res.gains.at[bad_row, 0].set(jnp.inf))
+        return res, ss
+
+    monkeypatch.setattr(svc_mod, "summarize_batch", poisoned)
+    svc = SummarizeService(RunConfig(max_batch=4, retry_backoff_s=0.002))
+    out = svc.run(list(reqs))
+    for a, b in zip(out, ref):
+        assert_same_results(a, b)
+    assert buckets[0] == 4
+    st = svc.stats()
+    assert st["batches"] == 1 and st["queries"] == 3
+    if caught:
+        assert len(buckets) == 2 and st["retries"] == 1
+        assert all(r.recovery["retries"] == 1 for r in out)
+    else:
+        assert len(buckets) == 1 and st["retries"] == 0
+        assert all(r.recovery is None for r in out)
+
+
 def test_failover_after_retry_exhaustion():
     reqs = [req(i) for i in range(4)]
     ref = SummarizeService(RunConfig(max_batch=4)).run(list(reqs))
